@@ -44,6 +44,9 @@ PAD_KEY = jnp.iinfo(jnp.int64).max  # sorts after every real key
 # workspace when the caller-declared key space fits this many slots
 # (a 4 MB f32 accumulator at the limit)
 DENSE_REDUCE_BOUND = 1 << 20
+# keys below this bound sort as int32 (padding maps onto the bound itself,
+# so it still sorts after every live key)
+I32_SORT_BOUND = np.iinfo(np.int32).max
 
 
 def exclusive_cumsum(x):
@@ -203,7 +206,14 @@ def keyed_union_reduce(keys, vals, valid, cap: int, segment_sum_impl=None,
         return (jnp.where(out_valid, uk, PAD_KEY),
                 jnp.where(out_valid, uv, 0.0), out_valid, count)
     keys = jnp.where(valid, keys, PAD_KEY)
-    order = jnp.argsort(keys)
+    if key_bound is not None and int(key_bound) < I32_SORT_BOUND:
+        # the same stable permutation from 32-bit keys: a 64-bit sort is
+        # emulated on TPU and compiles ~10x slower (minutes at 512k keys)
+        k32 = jnp.where(valid, keys, I32_SORT_BOUND).astype(I32)
+        _, order = jax.lax.sort((k32, jax.lax.iota(I32, keys.shape[0])),
+                                num_keys=1, is_stable=True)
+    else:
+        order = jnp.argsort(keys)
     sk = keys[order]
     sv = jnp.where(valid[order], vals[order], 0.0)
     first = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]])
@@ -276,19 +286,35 @@ def accumulate_coo(acc_keys, acc_vals, keys, vals, key_bound=None,
     ``(keys, vals)`` sorted by key, unique. ``union_reduce_impl`` routes
     the merge through a dispatch-table implementation (the Pallas
     dense-workspace kernel on TPU); None keeps this module's fallback.
+
+    The merge is one jitted call over inputs padded to a power-of-two
+    bucket (padding rows are invalid), so a tile stream compiles once per
+    bucket instead of once per partial size for every op of the reduce.
     """
-    k = jnp.concatenate([jnp.asarray(acc_keys, I64), jnp.asarray(keys, I64)])
-    v = jnp.concatenate([jnp.asarray(acc_vals, jnp.float32),
-                         jnp.asarray(vals, jnp.float32)])
-    if k.shape[0] == 0:
+    n_acc, n = len(acc_keys), len(acc_keys) + len(keys)
+    if n == 0:
         return (np.zeros(0, np.int64), np.zeros(0, np.float32))
-    cap = max(8, 1 << (int(k.shape[0]) - 1).bit_length())
+    cap = max(8, 1 << (n - 1).bit_length())
+    k = np.full(cap, PAD_KEY, np.int64)
+    k[:n_acc], k[n_acc:n] = acc_keys, keys
+    v = np.zeros(cap, np.float32)
+    v[:n_acc], v[n_acc:n] = acc_vals, vals
+    uk, uv, count = _merge_bucket(
+        k, v, np.arange(cap) < n, cap=cap, key_bound=key_bound,
+        segment_sum_impl=segment_sum_impl,
+        union_reduce_impl=union_reduce_impl)
+    n_out = int(count)
+    return np.asarray(uk[:n_out]), np.asarray(uv[:n_out])
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "cap", "key_bound", "segment_sum_impl", "union_reduce_impl"))
+def _merge_bucket(k, v, valid, *, cap, key_bound, segment_sum_impl,
+                  union_reduce_impl):
     union_reduce = union_reduce_impl or keyed_union_reduce
-    uk, uv, _, count = union_reduce(
-        k, v, jnp.ones(k.shape, bool), cap, segment_sum_impl,
-        key_bound=key_bound)
-    n = int(count)
-    return np.asarray(uk[:n]), np.asarray(uv[:n])
+    uk, uv, _, count = union_reduce(k, v, valid, cap, segment_sum_impl,
+                                    key_bound=key_bound)
+    return uk, uv, count
 
 
 def convert_level(level, num_parents: int):

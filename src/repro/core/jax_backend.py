@@ -65,19 +65,16 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
+from ..kernels import ops as kops
 from . import coord_ops as co
 from . import graph as g
 from .custard import expr_cache_key, lower
 from .einsum import Assignment, parse
 from .fibertree import BITVECTOR, COMPRESSED, DENSE, FiberTree, canonical_tree
 from .schedule import Format, Schedule
-
-try:  # moved to the jax namespace in newer releases
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-from jax.sharding import Mesh, PartitionSpec as P
 
 PAD = co.PAD_KEY
 
@@ -781,14 +778,10 @@ class CompiledExpr:
         self._union_reduce = None
         self._mul_reduce = None
         if use_kernels:
-            try:
-                from ..kernels import ops as kops
-                self._segsum = kops.sam_primitive("keyed_segment_sum")
-                self._intersect = kops.sam_primitive("sorted_intersect")
-                self._union_reduce = kops.sam_primitive("keyed_union_reduce")
-                self._mul_reduce = kops.sam_primitive("mul_reduce")
-            except ImportError:      # kernels layer unavailable: coord_ops
-                pass
+            self._segsum = kops.sam_primitive("keyed_segment_sum")
+            self._intersect = kops.sam_primitive("sorted_intersect")
+            self._union_reduce = kops.sam_primitive("keyed_union_reduce")
+            self._mul_reduce = kops.sam_primitive("mul_reduce")
         self._level_meta: Dict[str, List[Tuple[str, int]]] = {}
         self._plans: Dict[Tuple, _Plan] = {}
         self._batch_plans: Dict[Tuple, _Plan] = {}
@@ -802,6 +795,12 @@ class CompiledExpr:
         self.stats = {"traces": 0, "plan_hits": 0, "plan_misses": 0,
                       "overflow_retries": 0, "calls": 0, "batch_calls": 0,
                       "lane_dispatches": 0, "sharded_dispatches": 0}
+
+    @property
+    def lane_devices(self) -> List[Any]:
+        """The devices the parallel lanes shard over (empty when the
+        lanes vmap on one device)."""
+        return jax.devices()[:self._lane_mesh] if self._shard_lanes else []
 
     def _build_out_merge(self):
         """Decode plan for split result levels: [(orig var, o-col, i-col or
@@ -885,19 +884,18 @@ class CompiledExpr:
         vm = jax.vmap(fn)
         if not shard:
             return vm
-        mesh = Mesh(np.asarray(jax.devices()[:self._lane_mesh]), ("lanes",))
+        mesh = Mesh(np.asarray(self.lane_devices), ("lanes",))
         return _shard_map(vm, mesh=mesh, in_specs=P("lanes"),
-                          out_specs=P("lanes"), check_rep=False)
+                          out_specs=P("lanes"), check_vma=False)
 
     def _build_core(self, caps: Dict[str, int], batch: bool) -> Callable:
-        # Pallas-backed impls are dispatched per single execution; the
-        # vmapped batch path keeps the plain-jnp fallbacks (pallas_call
-        # batching is not guaranteed in interpret mode).
-        segsum = None if batch else self._segsum
-        intersect = None if batch else self._intersect
-        mul_reduce = None if batch else self._mul_reduce
-        union_reduce = ((None if batch else self._union_reduce)
-                        or co.keyed_union_reduce)
+        # the single and the vmapped batch path run the same resolved
+        # primitives: Pallas kernels on TPU (pallas_call batches by adding
+        # a grid axis), the coord_ops fallbacks elsewhere
+        segsum = self._segsum
+        intersect = self._intersect
+        mul_reduce = self._mul_reduce
+        union_reduce = self._union_reduce or co.keyed_union_reduce
         scan_caps = [
             {n.id: caps[f"t{ti}.s{n.id}"] for n in G.of_kind(g.LEVEL_SCAN)}
             for ti, G in enumerate(self.graphs)]
@@ -1217,6 +1215,13 @@ class CompiledExpr:
         return self._run_plan(plan, enc.sig, enc.stacked, batch=True,
                               b_pad=enc.b_pad)
 
+    def batch_plan_text(self, enc: "EncodedBatch") -> str:
+        """Compiled program text of the batch plan that ``enc`` dispatches
+        to (``execute_encoded`` installs it): shows which kernels the
+        served plan runs — a Pallas kernel is a ``tpu_custom_call``."""
+        plan = self._batch_plans[(enc.sig, enc.b_pad)]
+        return plan.fn.lower(enc.stacked).compile().as_text()
+
     def decode_batch(self, enc: "EncodedBatch", out) -> List[FiberTree]:
         """Host-side stage 3: assemble one ``FiberTree`` per live batch
         member (batch-axis padding dropped).
@@ -1330,20 +1335,17 @@ class TiledExpr:
                                    shard_lanes=shard_lanes)
         # tile-merge stage impl: the Pallas dense-workspace kernel on TPU
         # (same dispatch entry as the engine's lane/term merge)
-        self._union_reduce = None
-        if use_kernels:
-            try:
-                from ..kernels import ops as kops
-                self._union_reduce = kops.sam_primitive("keyed_union_reduce")
-            except ImportError:
-                pass
+        self._union_reduce = (kops.sam_primitive("keyed_union_reduce")
+                              if use_kernels else None)
         self.rvars = self.engine.orig_result_order   # orig vars, loop order
         self._scalar = not self.rvars
         self._out_strides = [(v, self.dims[v]) for v in self.rvars]
         bound = 1
         for _, d in self._out_strides:
             bound *= d
-        self._key_bound = bound if bound <= co.DENSE_REDUCE_BOUND else None
+        # the merge's key space: small bounds take the dense workspace,
+        # larger ones a 32-bit-key sort (see coord_ops.keyed_union_reduce)
+        self._key_bound = bound
         # running max input-bucket per (tensor, level) across tiles, so
         # EVERY tile pads to one shared signature and hits one plan
         self._hints: Dict[str, List[int]] = {}
@@ -1488,7 +1490,8 @@ def compile_expr(expr, fmt: Format, schedule,
             shape is searched at most once per cache; DESIGN.md §5).
         dims: extent of every index variable.
         use_kernels: route hot primitives through the ``kernels/``
-            dispatch table (Pallas on TPU) when available.
+            dispatch table (Pallas on TPU, the coord_ops fallbacks
+            elsewhere).
         shard_lanes: §4.4 lane placement — None auto-shards over a device
             mesh when one fits, False forces a single-device vmap,
             True/int requires a mesh (of at most that many devices).
@@ -1889,13 +1892,9 @@ class CompiledProgram:
         self.mem_budget = mem_budget
         segsum = intersect = coo_levels = None
         if use_kernels:
-            try:
-                from ..kernels import ops as kops
-                segsum = kops.sam_primitive("keyed_segment_sum")
-                intersect = kops.sam_primitive("sorted_intersect")
-                coo_levels = kops.sam_primitive("coo_to_levels")
-            except ImportError:
-                pass
+            segsum = kops.sam_primitive("keyed_segment_sum")
+            intersect = kops.sam_primitive("sorted_intersect")
+            coo_levels = kops.sam_primitive("coo_to_levels")
         self.units: List[Tuple[str, List[int], Any]] = []
         for comp in lp.components():
             if len(comp) == 1:

@@ -302,18 +302,21 @@ class SamServer:
         self._last_done_t: Optional[float] = None
 
     def _ensure_threads(self) -> None:
-        """Start the pipeline lazily on first threaded submit."""
-        if self._sync or self._threads:
-            return
-        self._stage_qs = [queue.Queue(self._depth) for _ in range(3)]
-        stages = [("sam-serve-batcher", self._batcher_loop),
-                  ("sam-serve-encode", self._encode_loop),
-                  ("sam-serve-dispatch", self._dispatch_loop),
-                  ("sam-serve-decode", self._decode_loop)]
-        for name, fn in stages:
-            t = threading.Thread(target=fn, name=name, daemon=True)
-            t.start()
-            self._threads.append(t)
+        """Start the pipeline lazily on first threaded submit. Held under
+        the server lock: submitters race here, and a second pipeline
+        started over the first one's queues deadlocks both."""
+        with self._lock:
+            if self._sync or self._threads:
+                return
+            self._stage_qs = [queue.Queue(self._depth) for _ in range(3)]
+            stages = [("sam-serve-batcher", self._batcher_loop),
+                      ("sam-serve-encode", self._encode_loop),
+                      ("sam-serve-dispatch", self._dispatch_loop),
+                      ("sam-serve-decode", self._decode_loop)]
+            for name, fn in stages:
+                t = threading.Thread(target=fn, name=name, daemon=True)
+                t.start()
+                self._threads.append(t)
 
     def shutdown(self, drain: bool = True) -> None:
         """Stop the server. ``drain=True`` (graceful, the default)

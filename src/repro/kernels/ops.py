@@ -7,6 +7,7 @@ coordinates, sentinel padding, causal local masks).
 """
 from __future__ import annotations
 
+from collections import Counter
 from typing import Optional, Tuple
 
 import jax
@@ -114,6 +115,7 @@ def segment_reduce(vals, seg_ids, *, num_segments: int, t_tile: int = 512,
 # ``sam_primitive(name)`` picks the implementation for the active backend;
 # every TPU entry guards its crossover threshold and falls back to the
 # coord_ops implementation outside it, so dispatch is always safe.
+# ``TRACED`` counts which side of that guard each TPU entry took.
 
 from ..core import coord_ops as _co
 from .coo_levels import MAX_EXACT_COORD as _MAX_EXACT_COORD
@@ -134,6 +136,16 @@ _PALLAS_WORKSPACE_MAX_SLOTS = 4096
 # back rather than silently narrowing through float32
 _PALLAS_EXACT_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float16)
 
+# (primitive, "pallas" | "fallback") -> how many times a TPU entry was
+# traced into a program on that side of its guard (tracing happens once
+# per compiled plan, so this says which implementation each plan runs)
+TRACED: Counter = Counter()
+
+
+def _took(name: str, pallas: bool) -> bool:
+    TRACED[(name, "pallas" if pallas else "fallback")] += 1
+    return pallas
+
 
 def _keyed_segment_sum_pallas(vals, seg_ids, num_segments: int):
     """1-D keyed segment-sum via the tiled MXU segment_reduce kernel.
@@ -142,8 +154,9 @@ def _keyed_segment_sum_pallas(vals, seg_ids, num_segments: int):
     is exact for f32/bf16/f16 inputs but would silently narrow f64 (and
     round large ints), so those dtypes route to the fallback.
     """
-    if (num_segments > _PALLAS_SEGSUM_MAX_SEGMENTS
-            or vals.dtype not in _PALLAS_EXACT_DTYPES):
+    if not _took("keyed_segment_sum",
+                 num_segments <= _PALLAS_SEGSUM_MAX_SEGMENTS
+                 and vals.dtype in _PALLAS_EXACT_DTYPES):
         return _co.default_segment_sum(vals, seg_ids, num_segments)
     out = segment_reduce(vals[:, None], seg_ids, num_segments=num_segments)
     return out[:, 0]
@@ -179,7 +192,7 @@ def _keyed_union_reduce_pallas(keys, vals, valid, cap: int,
     Unknown/large key bounds and non-f32 values keep the coord_ops
     sort-merge fallback.
     """
-    if not _workspace_ok(vals, key_bound):
+    if not _took("keyed_union_reduce", _workspace_ok(vals, key_bound)):
         return _co.keyed_union_reduce(keys, vals, valid, cap,
                                       segment_sum_impl, key_bound=key_bound)
     nseg = max(int(key_bound), 1)
@@ -197,7 +210,7 @@ def _mul_reduce_pallas(keys, a_vals, b_vals, valid, cap: int, *,
     """Fused multiply × keyed reduce: the product is formed inside the
     workspace kernel (``mul_pair`` payload), so the engine's deferred
     mul-ALU never materializes a product stream."""
-    if not _workspace_ok(a_vals, key_bound):
+    if not _took("mul_reduce", _workspace_ok(a_vals, key_bound)):
         return _co.mul_reduce(keys, a_vals, b_vals, valid, cap,
                               key_bound=key_bound,
                               segment_sum_impl=segment_sum_impl)
@@ -218,7 +231,7 @@ def _fused_imr_pallas(a_key, a_valid, a_vals, b_key, b_valid, b_vals,
     ``fused_stream``). Falls back outside the dense-workspace guard; the
     kernel's stream contract (int32 keys, strictly-increasing valid keys,
     prefix-valid b) is the level-scanner shape the engine produces."""
-    if not _workspace_ok(a_vals, key_bound):
+    if not _took("intersect_mul_reduce", _workspace_ok(a_vals, key_bound)):
         return _co.fused_intersect_mul_reduce(
             a_key, a_valid, a_vals, b_key, b_valid, b_vals, out_key, cap,
             key_bound=key_bound, segment_sum_impl=segment_sum_impl)
@@ -236,9 +249,10 @@ def _fused_imr_pallas(a_key, a_valid, a_vals, b_key, b_valid, b_vals,
 def _coo_to_levels_pallas(keys, valid, dims_list, caps):
     """Pallas-compacted COO→levels; the guard keeps every coordinate and
     capacity inside the exact-f32 horizon and the workspace VMEM budget."""
-    if (any(c > _PALLAS_WORKSPACE_MAX_SLOTS for c in caps)
-            or any(d >= _MAX_EXACT_COORD for d in dims_list)
-            or any(c >= _MAX_EXACT_COORD for c in caps)):
+    if not _took("coo_to_levels",
+                 all(c <= _PALLAS_WORKSPACE_MAX_SLOTS for c in caps)
+                 and all(d < _MAX_EXACT_COORD for d in dims_list)
+                 and all(c < _MAX_EXACT_COORD for c in caps)):
         return _co.coo_to_levels(keys, valid, dims_list, caps)
     return _coo_to_levels_kernel(keys, valid, dims_list, caps,
                                  interpret=_auto_interpret(None))

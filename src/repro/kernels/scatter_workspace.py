@@ -53,8 +53,12 @@ def _kernel(ids_ref, cols_ref, o_ref, acc_ref, *, n_slots, t, mul_pair):
         cols = jnp.concatenate([prod, mask.astype(jnp.float32)], axis=1)
     seg_iota = jax.lax.broadcasted_iota(jnp.int32, (n_slots, t), 0)
     onehot = (seg_iota == ids[None, :]).astype(jnp.float32)  # (S, T)
+    # HIGHEST: the payload must cross the MXU at full f32 width — a
+    # single bf16 pass would round every value (and every packed
+    # coordinate of the coo_to_levels move) to 8 mantissa bits
     acc_ref[...] += jnp.dot(onehot, cols,
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(nt == pl.num_programs(0) - 1)
     def _():
@@ -84,15 +88,18 @@ def scatter_workspace(ids: jnp.ndarray, cols: jnp.ndarray, *,
     ids2d = ids.astype(jnp.int32).reshape(1, n_p)
     c_out = 2 if mul_pair else c
 
+    # block indices are int32: under 64-bit mode a bare 0 in an index
+    # map lowers as i64, which Mosaic cannot legalize
     out = pl.pallas_call(
         functools.partial(_kernel, n_slots=s_p, t=t_tile,
                           mul_pair=mul_pair),
         grid=(n_p // t_tile,),
         in_specs=[
-            pl.BlockSpec((1, t_tile), lambda nt: (0, nt)),
-            pl.BlockSpec((t_tile, c), lambda nt: (nt, 0)),
+            pl.BlockSpec((1, t_tile), lambda nt: (jnp.int32(0), nt)),
+            pl.BlockSpec((t_tile, c), lambda nt: (nt, jnp.int32(0))),
         ],
-        out_specs=pl.BlockSpec((s_p, c_out), lambda nt: (0, 0)),
+        out_specs=pl.BlockSpec((s_p, c_out),
+                               lambda nt: (jnp.int32(0), jnp.int32(0))),
         out_shape=jax.ShapeDtypeStruct((s_p, c_out), jnp.float32),
         scratch_shapes=[pltpu.VMEM((s_p, c_out), jnp.float32)],
         interpret=interpret,

@@ -35,8 +35,10 @@ def _kernel(ids_ref, vals_ref, o_ref, acc_ref, *, n_seg, t):
     ids = ids_ref[0]                          # (T,)
     seg_iota = jax.lax.broadcasted_iota(jnp.int32, (n_seg, t), 0)
     onehot = (seg_iota == ids[None, :]).astype(jnp.float32)   # (S, T)
+    # HIGHEST: full-f32 MXU passes, so the segment sums are f32-exact
     acc_ref[...] += jnp.dot(onehot, vals_ref[...].astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(nt == pl.num_programs(1) - 1)
     def _():
@@ -64,14 +66,17 @@ def segment_reduce(vals: jnp.ndarray, seg_ids: jnp.ndarray, *,
     ids2d = seg_ids.astype(jnp.int32).reshape(1, n_p)
 
     grid = (d_p // d_tile, n_p // t_tile)
+    # block indices are int32: under 64-bit mode a bare 0 in an index
+    # map lowers as i64, which Mosaic cannot legalize
     out = pl.pallas_call(
         functools.partial(_kernel, n_seg=s_p, t=t_tile),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, t_tile), lambda dj, nt: (0, nt)),
+            pl.BlockSpec((1, t_tile), lambda dj, nt: (jnp.int32(0), nt)),
             pl.BlockSpec((t_tile, d_tile), lambda dj, nt: (nt, dj)),
         ],
-        out_specs=pl.BlockSpec((s_p, d_tile), lambda dj, nt: (0, dj)),
+        out_specs=pl.BlockSpec((s_p, d_tile),
+                               lambda dj, nt: (jnp.int32(0), dj)),
         out_shape=jax.ShapeDtypeStruct((s_p, d_p), vals.dtype),
         scratch_shapes=[pltpu.VMEM((s_p, d_tile), jnp.float32)],
         interpret=interpret,

@@ -300,9 +300,10 @@ def serve_sam(expr: str, order: str, formats, dims, *, batch: int = 8,
             for _ in range(batch * max(reps, 1))]
     handles = srv.submit_many(reqs, engine=eng)
     srv.drain(timeout=600)
-    results = [h.result() for h in handles[-batch:]]
     sstats = srv.stats()
     srv.shutdown()
+    _raise_failed(handles)
+    results = [h.result() for h in handles[-batch:]]
     log(f"[serve-sam] {expr!r}: {sstats['completed']} requests in "
         f"{sstats['dispatches']} dispatches "
         f"(occupancy {sstats['batch_occupancy']:.1f}): "
@@ -310,6 +311,15 @@ def serve_sam(expr: str, order: str, formats, dims, *, batch: int = 8,
         f"p50={sstats['p50_ms']:.1f}ms p99={sstats['p99_ms']:.1f}ms")
     log(f"[serve-sam] engine stats: {eng.stats}")
     return results, eng.stats
+
+
+def _raise_failed(handles) -> None:
+    """A served request that failed fails the run: the server keeps going
+    past a failed dispatch group, the launcher must not."""
+    errors = [e for e in (h.exception() for h in handles) if e is not None]
+    if errors:
+        raise RuntimeError(f"{len(errors)} of {len(handles)} served "
+                           f"requests failed") from errors[0]
 
 
 def serve_program(text: str, formats, dims, *, batch: int = 8,
@@ -373,9 +383,10 @@ def serve_program(text: str, formats, dims, *, batch: int = 8,
             for _ in range(batch * max(reps, 1))]
     handles = srv.submit_many(reqs, engine=cp)
     srv.drain(timeout=600)
-    results = [h.result() for h in handles[-batch:]]
     sstats = srv.stats()
     srv.shutdown()
+    _raise_failed(handles)
+    results = [h.result() for h in handles[-batch:]]
     log(f"[serve-program] {len(prog.assigns)} stages, outputs="
         f"{','.join(prog.outputs)}: {sstats['completed']} requests in "
         f"{sstats['dispatches']} dispatches: "
@@ -491,4 +502,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

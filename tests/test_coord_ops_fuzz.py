@@ -81,6 +81,25 @@ def test_keyed_union_reduce_overflow_reports_true_count(case):
         assert int(count) == len(acc)   # overflow detectable, never silent
 
 
+@settings(max_examples=12, deadline=None)
+@given(keyed_stream(), hst.integers(co.DENSE_REDUCE_BOUND + 1,
+                                    co.I32_SORT_BOUND - 1))
+def test_keyed_union_reduce_int32_sort_is_bit_identical(case, big_bound):
+    """Past the dense bound, a key bound below 2**31 - 1 sorts 32-bit
+    keys; the permutation, and so every output bit, is the 64-bit
+    sort's. Keys spread over the whole bound."""
+    keys, vals, valid, bound = case
+    keys = keys * (big_bound // bound) + (keys % 7)
+    assert keys.max() < big_bound
+    cap = max(8, len(keys))
+    args = (jnp.asarray(keys, jnp.int64), jnp.asarray(vals),
+            jnp.asarray(valid), cap)
+    ref = co.keyed_union_reduce(*args, key_bound=None)
+    got = co.keyed_union_reduce(*args, key_bound=big_bound)
+    for a, b in zip(ref, got):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
 def test_keyed_union_reduce_empty_stream():
     for key_bound in (None, 16):
         uk, uv, ok, count = co.keyed_union_reduce(

@@ -121,7 +121,7 @@ _SUBPROC_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, json
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 from repro.distributed.sharding import params_shardings, set_activation_policy
 from repro.distributed.elastic import reshard, validate_mesh_for, shrink_mesh
 from repro.configs import get_config
@@ -132,7 +132,9 @@ from repro.configs.base import ShapeConfig
 cfg = get_config("qwen3-0.6b", reduced=True)
 params = init_params(cfg, jax.random.PRNGKey(0))
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+# sharding constraints consume these meshes: their axes must be Auto
+auto = (AxisType.Auto,) * 2
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=auto)
 assert not validate_mesh_for(params, mesh)
 sh = params_shardings(params, mesh)
 params = jax.device_put(params, sh)
@@ -144,7 +146,7 @@ loss, grads = jax.jit(jax.value_and_grad(
 assert np.isfinite(float(loss))
 
 # elastic: move the whole state onto a different mesh layout
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+mesh2 = jax.make_mesh((2, 4), ("data", "model"), axis_types=auto)
 params2 = reshard(params, mesh2)
 l2 = jax.jit(lambda p: loss_fn(cfg, p, batch))(params2)
 np.testing.assert_allclose(float(l2), float(loss), rtol=1e-3)

@@ -361,3 +361,51 @@ def test_result_handle_timeout_and_exception_surface():
     assert h.exception() is err
     with pytest.raises(AdmissionError):
         h.result()
+
+
+def test_launcher_fails_the_run_when_a_served_request_failed():
+    """The server keeps going past a failed group; the launcher
+    (``launch/serve.py``) turns any failed request into an error."""
+    from repro.launch.serve import _raise_failed
+
+    rng = np.random.default_rng(7)
+    eng = _mv_engine()
+    srv = SamServer(sync=True, max_batch=1, clock=FakeClock())
+    good = _ops_mv(rng)
+    missing_c = {"B": good["B"]}
+    handles = srv.submit_many([Request(MV, good), Request(MV, missing_c)],
+                              engine=eng)
+    srv.drain()
+    assert srv.stats()["failed"] == 1 and srv.stats()["completed"] == 1
+    _raise_failed(handles[:1])                       # all served: no error
+    with pytest.raises(RuntimeError, match="1 of 2 served requests failed"):
+        _raise_failed(handles)
+
+
+def test_concurrent_first_submits_start_one_pipeline():
+    """Submitters race to start the lazy pipeline; exactly one set of
+    stage threads may start (two sets over one set of queues deadlock)."""
+    import sys
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for _ in range(5):
+            srv = SamServer(max_batch=2)
+            start = threading.Barrier(16)
+
+            def race():
+                start.wait(timeout=60)
+                srv._ensure_threads()
+
+            racers = [threading.Thread(target=race) for _ in range(16)]
+            for t in racers:
+                t.start()
+            for t in racers:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in racers)
+            assert len(srv._threads) == 4
+            srv.shutdown()
+            assert not srv._threads
+    finally:
+        sys.setswitchinterval(old)
