@@ -56,16 +56,31 @@ estimate exceeds the budget — streams coordinate-space tiles
 sequentially through ONE shared per-tile ``CompiledExpr`` (every tile
 after the first hits the plan cache) and folds each tile's partial COO
 into the running result with ``coord_ops.accumulate_coo``.
+
+**Tracing.** Host work runs under ``jax.profiler.TraceAnnotation`` spans
+named ``sam.*`` (encode: ``sam.encode.build``/``sam.encode.pack``;
+execute: ``sam.plan.miss``, ``sam.caps``, ``sam.execute.launch``/
+``sam.execute.sync``/``sam.execute.regrow``; decode:
+``sam.decode.fetch``/``sam.decode.assemble``). Inside a compiled plan
+every graph node runs under a ``jax.named_scope`` ``sam.<kind>.n<id>``
+(the final keyed reduce under ``sam.collapse``, the lane/term union
+under ``sam.merge``), so device ops carry the node they compute; the
+eager capacity pass runs with no scope. ``stats["caps_passes"]`` and
+``stats["caps_s"]`` count the capacity passes and their seconds.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map as _shard_map
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..kernels import ops as kops
@@ -279,6 +294,14 @@ class JaxBackend:
     def _cap(n: int) -> int:
         return max(8, int(np.ceil(n / 8)) * 8)
 
+    def _scope(self, name: str):
+        """``jax.named_scope(name)`` inside a compiled plan (static mode);
+        none in the eager capacity pass, whose small programs keep their
+        compile-cache keys."""
+        if self.scan_caps is None:
+            return contextlib.nullcontext()
+        return jax.named_scope(name)
+
     # -- handlers -------------------------------------------------------
     def _root(self, node, ins):
         return {"ref": RefStream(None, jnp.zeros((1,), jnp.int32),
@@ -418,6 +441,10 @@ class JaxBackend:
         return out
 
     def _collapse_to_result(self, v: ValStream) -> COOResult:
+        with self._scope("sam.collapse"):
+            return self._collapse(v)
+
+    def _collapse(self, v: ValStream) -> COOResult:
         cs = v.stream
         chain = cs.ancestors()           # innermost first
         strides: List[Tuple[str, int]] = []
@@ -490,7 +517,8 @@ class JaxBackend:
             g.CONVERT: self._convert,
         }
         for node in self.g.topo_order():
-            outs = handlers[node.kind](node, self._ins(node))
+            with self._scope(f"sam.{node.kind}.n{node.id}"):
+                outs = handlers[node.kind](node, self._ins(node))
             for port, val in outs.items():
                 self.env[(node.id, port)] = val
 
@@ -590,17 +618,37 @@ def _run_with_growth(plan: _Plan, flat, stats: Dict[str, int],
     ``reinstall`` builds the replacement plan for the grown caps.
     """
     for _ in range(32):
-        out, required = plan.fn(flat)
+        with TraceAnnotation("sam.execute.launch"):
+            out, required = plan.fn(flat)
+        with TraceAnnotation("sam.execute.sync"):
+            required = jax.device_get(required)
         grow = {}
-        for k, r in jax.device_get(required).items():
+        for k, r in required.items():
             need = int(np.max(r))
             if need > plan.caps[k]:
                 grow[k] = _bucket_cap(need)
         if not grow:
             return out
         stats["overflow_retries"] += 1
-        plan = reinstall({**plan.caps, **grow})
+        with TraceAnnotation("sam.execute.regrow"):
+            plan = reinstall({**plan.caps, **grow})
     raise RuntimeError("compiled SAM capacity growth did not converge")
+
+
+def _capacity_pass(record_caps: Callable) -> Callable:
+    """Wraps an engine's ``_record_caps``: the eager capacity pass runs
+    under the ``sam.caps`` span and counts in the engine's ``stats``
+    (``caps_passes`` calls, ``caps_s`` seconds)."""
+    @functools.wraps(record_caps)
+    def counted(self, *args):
+        t = time.perf_counter()
+        try:
+            with TraceAnnotation("sam.caps"):
+                return record_caps(self, *args)
+        finally:
+            self.stats["caps_passes"] += 1
+            self.stats["caps_s"] += time.perf_counter() - t
+    return counted
 
 
 def _raw_flat_of(ft: FiberTree) -> Dict[str, Any]:
@@ -778,10 +826,10 @@ class CompiledExpr:
         self._union_reduce = None
         self._mul_reduce = None
         if use_kernels:
-            self._segsum = kops.sam_primitive("keyed_segment_sum")
-            self._intersect = kops.sam_primitive("sorted_intersect")
-            self._union_reduce = kops.sam_primitive("keyed_union_reduce")
-            self._mul_reduce = kops.sam_primitive("mul_reduce")
+            self._segsum = kops.plan_primitive("keyed_segment_sum")
+            self._intersect = kops.plan_primitive("sorted_intersect")
+            self._union_reduce = kops.plan_primitive("keyed_union_reduce")
+            self._mul_reduce = kops.plan_primitive("mul_reduce")
         self._level_meta: Dict[str, List[Tuple[str, int]]] = {}
         self._plans: Dict[Tuple, _Plan] = {}
         self._batch_plans: Dict[Tuple, _Plan] = {}
@@ -794,7 +842,8 @@ class CompiledExpr:
         self._hint_highwater: Dict[str, List[int]] = {}
         self.stats = {"traces": 0, "plan_hits": 0, "plan_misses": 0,
                       "overflow_retries": 0, "calls": 0, "batch_calls": 0,
-                      "lane_dispatches": 0, "sharded_dispatches": 0}
+                      "lane_dispatches": 0, "sharded_dispatches": 0,
+                      "caps_passes": 0, "caps_s": 0.0}
 
     @property
     def lane_devices(self) -> List[Any]:
@@ -850,6 +899,7 @@ class CompiledExpr:
                 and (len(self.graphs) > 1
                      or any(n > 1 for n in self.lane_ns)))
 
+    @_capacity_pass
     def _record_caps(self, flats: Sequence[Dict]) -> Dict[str, int]:
         """Eager capacity-recording pass over one (or, batched, every)
         concrete padded operand set; returns bucketed static capacities.
@@ -964,8 +1014,10 @@ class CompiledExpr:
             bound = 1
             for _, d in self._strides:
                 bound *= d
-            uk, uv, uvalid, count = union_reduce(
-                keys, vals, valid, caps["fused"], segsum, key_bound=bound)
+            with jax.named_scope("sam.merge"):
+                uk, uv, uvalid, count = union_reduce(
+                    keys, vals, valid, caps["fused"], segsum,
+                    key_bound=bound)
             required["fused"] = count
             return {"keys": uk, "vals": uv, "valid": uvalid}, required
 
@@ -1113,8 +1165,9 @@ class CompiledExpr:
         plan = self._plans.get(sig)
         if plan is None:
             self.stats["plan_misses"] += 1
-            caps = self._record_caps([flat])
-            plan = self._install_plan(sig, caps, batch=False)
+            with TraceAnnotation("sam.plan.miss"):
+                caps = self._record_caps([flat])
+                plan = self._install_plan(sig, caps, batch=False)
         else:
             self.stats["plan_hits"] += 1
         return self._run_plan(plan, sig, flat, batch=False)
@@ -1173,28 +1226,35 @@ class CompiledExpr:
         the batch axis to a power of two, and stack. The result feeds
         ``execute_encoded``; no device compute beyond the array uploads
         happens here."""
-        raws = [self._raw_flat(a) for a in arrays_list]
-        hints = self._sticky_hints(raws)
-        # largest-nnz member, recorded pre-padding: capacity recording
-        # interprets just this one member eagerly (an O(batch) eager sweep
-        # would dominate plan installs at serving widths) and the growth
-        # loop heals any residual undershoot from the other members
-        rep = max(range(len(raws)),
-                  key=lambda i: sum(int(e["vals"].shape[0])
-                                    for e in raws[i].values()))
-        flats_sigs = [self._pad_flat(r, hints) for r in raws]
-        flats = [f for f, _ in flats_sigs]
-        sig = flats_sigs[0][1]
-        b = len(flats)
-        b_pad = _bucket_batch(b)
-        padded = flats
-        if b_pad > b:      # pad the dispatch with empty operand sets
-            filler = jax.tree_util.tree_map(np.zeros_like, flats[0])
-            padded = flats + [filler] * (b_pad - b)
-        # numpy stack: the ONE host->device upload happens at the jit
-        # call boundary in execute_encoded, keeping this stage pure host
-        # work that pipeline threads can overlap with device execution
-        stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *padded)
+        raws = []
+        for a in arrays_list:
+            with TraceAnnotation("sam.encode.build"):
+                raws.append(self._raw_flat(a))
+        with TraceAnnotation("sam.encode.pack"):
+            hints = self._sticky_hints(raws)
+            # largest-nnz member, recorded pre-padding: capacity recording
+            # interprets just this one member eagerly (an O(batch) eager
+            # sweep would dominate plan installs at serving widths) and
+            # the growth loop heals any residual undershoot from the
+            # other members
+            rep = max(range(len(raws)),
+                      key=lambda i: sum(int(e["vals"].shape[0])
+                                        for e in raws[i].values()))
+            flats_sigs = [self._pad_flat(r, hints) for r in raws]
+            flats = [f for f, _ in flats_sigs]
+            sig = flats_sigs[0][1]
+            b = len(flats)
+            b_pad = _bucket_batch(b)
+            padded = flats
+            if b_pad > b:      # pad the dispatch with empty operand sets
+                filler = jax.tree_util.tree_map(np.zeros_like, flats[0])
+                padded = flats + [filler] * (b_pad - b)
+            # numpy stack: the ONE host->device upload happens at the jit
+            # call boundary in execute_encoded, keeping this stage pure
+            # host work that pipeline threads can overlap with device
+            # execution
+            stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs),
+                                             *padded)
         return EncodedBatch(stacked=stacked, sig=sig, b=b, b_pad=b_pad,
                             flats=flats, rep=rep)
 
@@ -1207,9 +1267,10 @@ class CompiledExpr:
         plan = self._batch_plans.get((enc.sig, enc.b_pad))
         if plan is None:
             self.stats["plan_misses"] += 1
-            caps = self._record_caps([enc.flats[enc.rep]])
-            plan = self._install_plan(enc.sig, caps, batch=True,
-                                      b_pad=enc.b_pad)
+            with TraceAnnotation("sam.plan.miss"):
+                caps = self._record_caps([enc.flats[enc.rep]])
+                plan = self._install_plan(enc.sig, caps, batch=True,
+                                          b_pad=enc.b_pad)
         else:
             self.stats["plan_hits"] += 1
         return self._run_plan(plan, enc.sig, enc.stacked, batch=True,
@@ -1230,8 +1291,10 @@ class CompiledExpr:
         per-member loop: slicing device arrays member-by-member would pay
         a device op plus a blocking transfer per member, which dominates
         decode at serving batch widths."""
-        host = jax.device_get(out)
-        return [self._assemble_out(host, b=i) for i in range(enc.b)]
+        with TraceAnnotation("sam.decode.fetch"):
+            host = jax.device_get(out)
+        with TraceAnnotation("sam.decode.assemble"):
+            return [self._assemble_out(host, b=i) for i in range(enc.b)]
 
     def execute_batch(self, arrays_list: Sequence[Dict[str, np.ndarray]]
                       ) -> List[FiberTree]:
@@ -1729,7 +1792,8 @@ class _FusedChain:
         self._plans: Dict[Tuple, _Plan] = {}
         self._jit_cache: Dict[Tuple, Callable] = {}
         self.stats = {"traces": 0, "plan_hits": 0, "plan_misses": 0,
-                      "overflow_retries": 0, "calls": 0}
+                      "overflow_retries": 0, "calls": 0,
+                      "caps_passes": 0, "caps_s": 0.0}
 
     # -- operand flattening ------------------------------------------------
     def _raw_flat(self, env: Dict[str, np.ndarray]) -> Dict[str, Any]:
@@ -1773,6 +1837,7 @@ class _FusedChain:
         return JTensor(levels, vals), counts
 
     # -- capacity recording ------------------------------------------------
+    @_capacity_pass
     def _record_caps(self, flat) -> Dict[str, int]:
         caps: Dict[str, int] = {}
         inter: Dict[str, JTensor] = {}
@@ -1861,7 +1926,8 @@ class _FusedChain:
         plan = self._plans.get(sig)
         if plan is None:
             self.stats["plan_misses"] += 1
-            plan = self._install_plan(sig, self._record_caps(flat))
+            with TraceAnnotation("sam.plan.miss"):
+                plan = self._install_plan(sig, self._record_caps(flat))
         else:
             self.stats["plan_hits"] += 1
         out = self._run_plan(plan, sig, flat)
@@ -1892,8 +1958,8 @@ class CompiledProgram:
         self.mem_budget = mem_budget
         segsum = intersect = coo_levels = None
         if use_kernels:
-            segsum = kops.sam_primitive("keyed_segment_sum")
-            intersect = kops.sam_primitive("sorted_intersect")
+            segsum = kops.plan_primitive("keyed_segment_sum")
+            intersect = kops.plan_primitive("sorted_intersect")
             coo_levels = kops.sam_primitive("coo_to_levels")
         self.units: List[Tuple[str, List[int], Any]] = []
         for comp in lp.components():

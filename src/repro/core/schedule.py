@@ -18,6 +18,7 @@ import dataclasses
 from typing import Dict, FrozenSet, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .einsum import Assignment
 from .fibertree import FiberTree
@@ -231,29 +232,37 @@ def build_inputs(assign: Assignment, fmt: Format, schedule: Schedule,
                  arrays: Dict[str, np.ndarray],
                  split_of: Optional[Dict[str, int]] = None
                  ) -> Dict[str, FiberTree]:
-    """Construct concordant FiberTrees for every input tensor."""
+    """Construct concordant FiberTrees for every input tensor, each under
+    a ``sam.encode.tree`` profiler span whose ``tensor`` stat names it."""
     out: Dict[str, FiberTree] = {}
     split_of = split_of or {}
     for term in assign.terms:
         for acc in term.factors:
             if acc.tensor in out:
                 continue
-            arr = np.asarray(arrays[acc.tensor], dtype=np.float64)
-            # split vars: adjacent (vo, vi) pairs reshape the original axis
-            # into (factor, dim/factor) chunks; each loop step consumes ONE
-            # output axis (the vi half is its own iteration), so the cursor
-            # always advances by one
-            ax = 0
-            for v in acc.vars:
-                if (v.endswith("o") and v[:-1] in split_of
-                        and ax < arr.ndim):
-                    arr = split_dense(arr, ax, split_of[v[:-1]])
-                ax += 1
-            path = schedule.tensor_path(acc.vars)
-            mode_order = tuple(acc.vars.index(v) for v in path)
-            out[acc.tensor] = FiberTree.from_dense(
-                arr, fmt.of(acc.tensor, arr.ndim), mode_order=mode_order)
+            with TraceAnnotation("sam.encode.tree", tensor=acc.tensor):
+                out[acc.tensor] = _concordant_tree(acc, fmt, schedule,
+                                                   arrays, split_of)
     return out
+
+
+def _concordant_tree(acc, fmt: Format, schedule: Schedule,
+                     arrays: Dict[str, np.ndarray],
+                     split_of: Dict[str, int]) -> FiberTree:
+    arr = np.asarray(arrays[acc.tensor], dtype=np.float64)
+    # split vars: adjacent (vo, vi) pairs reshape the original axis into
+    # (factor, dim/factor) chunks; each loop step consumes ONE output axis
+    # (the vi half is its own iteration), so the cursor always advances
+    # by one
+    ax = 0
+    for v in acc.vars:
+        if v.endswith("o") and v[:-1] in split_of and ax < arr.ndim:
+            arr = split_dense(arr, ax, split_of[v[:-1]])
+        ax += 1
+    path = schedule.tensor_path(acc.vars)
+    mode_order = tuple(acc.vars.index(v) for v in path)
+    return FiberTree.from_dense(arr, fmt.of(acc.tensor, arr.ndim),
+                                mode_order=mode_order)
 
 
 def split_dense(arr: np.ndarray, axis: int, factor: int) -> np.ndarray:

@@ -7,6 +7,8 @@ coordinates, sentinel padding, causal local masks).
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 from collections import Counter
 from typing import Optional, Tuple
 
@@ -115,7 +117,11 @@ def segment_reduce(vals, seg_ids, *, num_segments: int, t_tile: int = 512,
 # ``sam_primitive(name)`` picks the implementation for the active backend;
 # every TPU entry guards its crossover threshold and falls back to the
 # coord_ops implementation outside it, so dispatch is always safe.
-# ``TRACED`` counts which side of that guard each TPU entry took.
+# ``TRACED`` counts which side of that guard each TPU entry took, and the
+# entry runs that side under a ``kops.<primitive>.<pallas|fallback>``
+# named scope; ``plan_primitive`` gives a fallback resolved directly the
+# same ``kops.<primitive>.fallback`` scope, so every device op a compiled
+# plan runs through this table names its primitive in the trace.
 
 from ..core import coord_ops as _co
 from .coo_levels import MAX_EXACT_COORD as _MAX_EXACT_COORD
@@ -142,9 +148,14 @@ _PALLAS_EXACT_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float16)
 TRACED: Counter = Counter()
 
 
-def _took(name: str, pallas: bool) -> bool:
-    TRACED[(name, "pallas" if pallas else "fallback")] += 1
-    return pallas
+@contextlib.contextmanager
+def _took(name: str, pallas: bool):
+    """Count which side of its guard a TPU entry took, and run that side
+    under the ``kops.<name>.<side>`` named scope; yields ``pallas``."""
+    side = "pallas" if pallas else "fallback"
+    TRACED[(name, side)] += 1
+    with jax.named_scope(f"kops.{name}.{side}"):
+        yield pallas
 
 
 def _keyed_segment_sum_pallas(vals, seg_ids, num_segments: int):
@@ -154,12 +165,14 @@ def _keyed_segment_sum_pallas(vals, seg_ids, num_segments: int):
     is exact for f32/bf16/f16 inputs but would silently narrow f64 (and
     round large ints), so those dtypes route to the fallback.
     """
-    if not _took("keyed_segment_sum",
-                 num_segments <= _PALLAS_SEGSUM_MAX_SEGMENTS
-                 and vals.dtype in _PALLAS_EXACT_DTYPES):
-        return _co.default_segment_sum(vals, seg_ids, num_segments)
-    out = segment_reduce(vals[:, None], seg_ids, num_segments=num_segments)
-    return out[:, 0]
+    with _took("keyed_segment_sum",
+               num_segments <= _PALLAS_SEGSUM_MAX_SEGMENTS
+               and vals.dtype in _PALLAS_EXACT_DTYPES) as pallas:
+        if not pallas:
+            return _co.default_segment_sum(vals, seg_ids, num_segments)
+        out = segment_reduce(vals[:, None], seg_ids,
+                             num_segments=num_segments)
+        return out[:, 0]
 
 
 def _dense_workspace_finalize(sums, hits, cap: int):
@@ -192,17 +205,20 @@ def _keyed_union_reduce_pallas(keys, vals, valid, cap: int,
     Unknown/large key bounds and non-f32 values keep the coord_ops
     sort-merge fallback.
     """
-    if not _took("keyed_union_reduce", _workspace_ok(vals, key_bound)):
-        return _co.keyed_union_reduce(keys, vals, valid, cap,
-                                      segment_sum_impl, key_bound=key_bound)
-    nseg = max(int(key_bound), 1)
-    ids = jnp.where(valid, keys, nseg).astype(jnp.int32)
-    v0 = jnp.where(valid, vals, jnp.zeros((), vals.dtype))
-    cols = jnp.stack([v0.astype(jnp.float32),
-                      valid.astype(jnp.float32)], axis=1)
-    ws = _scatter_workspace(ids, cols, num_slots=nseg,
-                            interpret=_auto_interpret(None))
-    return _dense_workspace_finalize(ws[:, 0], ws[:, 1], cap)
+    with _took("keyed_union_reduce", _workspace_ok(vals, key_bound)) \
+            as pallas:
+        if not pallas:
+            return _co.keyed_union_reduce(keys, vals, valid, cap,
+                                          segment_sum_impl,
+                                          key_bound=key_bound)
+        nseg = max(int(key_bound), 1)
+        ids = jnp.where(valid, keys, nseg).astype(jnp.int32)
+        v0 = jnp.where(valid, vals, jnp.zeros((), vals.dtype))
+        cols = jnp.stack([v0.astype(jnp.float32),
+                          valid.astype(jnp.float32)], axis=1)
+        ws = _scatter_workspace(ids, cols, num_slots=nseg,
+                                interpret=_auto_interpret(None))
+        return _dense_workspace_finalize(ws[:, 0], ws[:, 1], cap)
 
 
 def _mul_reduce_pallas(keys, a_vals, b_vals, valid, cap: int, *,
@@ -210,18 +226,19 @@ def _mul_reduce_pallas(keys, a_vals, b_vals, valid, cap: int, *,
     """Fused multiply × keyed reduce: the product is formed inside the
     workspace kernel (``mul_pair`` payload), so the engine's deferred
     mul-ALU never materializes a product stream."""
-    if not _took("mul_reduce", _workspace_ok(a_vals, key_bound)):
-        return _co.mul_reduce(keys, a_vals, b_vals, valid, cap,
-                              key_bound=key_bound,
-                              segment_sum_impl=segment_sum_impl)
-    nseg = max(int(key_bound), 1)
-    ids = jnp.where(valid, keys, nseg).astype(jnp.int32)
-    cols = jnp.stack([a_vals.astype(jnp.float32),
-                      b_vals.astype(jnp.float32),
-                      valid.astype(jnp.float32)], axis=1)
-    ws = _scatter_workspace(ids, cols, num_slots=nseg, mul_pair=True,
-                            interpret=_auto_interpret(None))
-    return _dense_workspace_finalize(ws[:, 0], ws[:, 1], cap)
+    with _took("mul_reduce", _workspace_ok(a_vals, key_bound)) as pallas:
+        if not pallas:
+            return _co.mul_reduce(keys, a_vals, b_vals, valid, cap,
+                                  key_bound=key_bound,
+                                  segment_sum_impl=segment_sum_impl)
+        nseg = max(int(key_bound), 1)
+        ids = jnp.where(valid, keys, nseg).astype(jnp.int32)
+        cols = jnp.stack([a_vals.astype(jnp.float32),
+                          b_vals.astype(jnp.float32),
+                          valid.astype(jnp.float32)], axis=1)
+        ws = _scatter_workspace(ids, cols, num_slots=nseg, mul_pair=True,
+                                interpret=_auto_interpret(None))
+        return _dense_workspace_finalize(ws[:, 0], ws[:, 1], cap)
 
 
 def _fused_imr_pallas(a_key, a_valid, a_vals, b_key, b_valid, b_vals,
@@ -231,31 +248,35 @@ def _fused_imr_pallas(a_key, a_valid, a_vals, b_key, b_valid, b_vals,
     ``fused_stream``). Falls back outside the dense-workspace guard; the
     kernel's stream contract (int32 keys, strictly-increasing valid keys,
     prefix-valid b) is the level-scanner shape the engine produces."""
-    if not _took("intersect_mul_reduce", _workspace_ok(a_vals, key_bound)):
-        return _co.fused_intersect_mul_reduce(
-            a_key, a_valid, a_vals, b_key, b_valid, b_vals, out_key, cap,
-            key_bound=key_bound, segment_sum_impl=segment_sum_impl)
-    sent = jnp.iinfo(jnp.int32).max
-    nseg = max(int(key_bound), 1)
-    ak = jnp.where(a_valid & (a_key != _co.PAD_KEY), a_key, sent)
-    bk = jnp.where(b_valid & (b_key != _co.PAD_KEY), b_key, sent)
-    bv = jnp.where(b_valid, b_vals, jnp.zeros((), b_vals.dtype))
-    ws = _fused_imr_workspace(ak, a_vals, jnp.clip(out_key, 0, nseg - 1),
-                              bk, bv, num_slots=nseg,
-                              interpret=_auto_interpret(None))
-    return _dense_workspace_finalize(ws[:, 0], ws[:, 1], cap)
+    with _took("intersect_mul_reduce", _workspace_ok(a_vals, key_bound)) \
+            as pallas:
+        if not pallas:
+            return _co.fused_intersect_mul_reduce(
+                a_key, a_valid, a_vals, b_key, b_valid, b_vals, out_key,
+                cap, key_bound=key_bound, segment_sum_impl=segment_sum_impl)
+        sent = jnp.iinfo(jnp.int32).max
+        nseg = max(int(key_bound), 1)
+        ak = jnp.where(a_valid & (a_key != _co.PAD_KEY), a_key, sent)
+        bk = jnp.where(b_valid & (b_key != _co.PAD_KEY), b_key, sent)
+        bv = jnp.where(b_valid, b_vals, jnp.zeros((), b_vals.dtype))
+        ws = _fused_imr_workspace(ak, a_vals,
+                                  jnp.clip(out_key, 0, nseg - 1),
+                                  bk, bv, num_slots=nseg,
+                                  interpret=_auto_interpret(None))
+        return _dense_workspace_finalize(ws[:, 0], ws[:, 1], cap)
 
 
 def _coo_to_levels_pallas(keys, valid, dims_list, caps):
     """Pallas-compacted COO→levels; the guard keeps every coordinate and
     capacity inside the exact-f32 horizon and the workspace VMEM budget."""
-    if not _took("coo_to_levels",
-                 all(c <= _PALLAS_WORKSPACE_MAX_SLOTS for c in caps)
-                 and all(d < _MAX_EXACT_COORD for d in dims_list)
-                 and all(c < _MAX_EXACT_COORD for c in caps)):
-        return _co.coo_to_levels(keys, valid, dims_list, caps)
-    return _coo_to_levels_kernel(keys, valid, dims_list, caps,
-                                 interpret=_auto_interpret(None))
+    with _took("coo_to_levels",
+               all(c <= _PALLAS_WORKSPACE_MAX_SLOTS for c in caps)
+               and all(d < _MAX_EXACT_COORD for d in dims_list)
+               and all(c < _MAX_EXACT_COORD for c in caps)) as pallas:
+        if not pallas:
+            return _co.coo_to_levels(keys, valid, dims_list, caps)
+        return _coo_to_levels_kernel(keys, valid, dims_list, caps,
+                                     interpret=_auto_interpret(None))
 
 
 SAM_PRIMITIVES = {
@@ -291,6 +312,21 @@ def sam_primitive(name: str, backend: Optional[str] = None):
     impls = SAM_PRIMITIVES[name]
     backend = backend or jax.default_backend()
     return impls.get(backend, impls["fallback"])
+
+
+def plan_primitive(name: str):
+    """``sam_primitive(name)`` as a compiled plan calls it: a TPU entry
+    names the side of its guard itself; a fallback resolved directly runs
+    under the ``kops.<name>.fallback`` named scope."""
+    impl = sam_primitive(name)
+    if impl is not SAM_PRIMITIVES[name]["fallback"]:
+        return impl
+
+    @functools.wraps(impl)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(f"kops.{name}.fallback"):
+            return impl(*args, **kwargs)
+    return scoped
 
 
 def register_primitive(name: str, backend: str, impl) -> None:

@@ -308,7 +308,8 @@ def serve_sam(expr: str, order: str, formats, dims, *, batch: int = 8,
         f"{sstats['dispatches']} dispatches "
         f"(occupancy {sstats['batch_occupancy']:.1f}): "
         f"{sstats['requests_per_sec']:.1f} req/s "
-        f"p50={sstats['p50_ms']:.1f}ms p99={sstats['p99_ms']:.1f}ms")
+        f"p50={sstats['p50_ms']:.1f}ms p99={sstats['p99_ms']:.1f}ms "
+        f"stage-wait p50={sstats['stage_wait_p50_ms']:.1f}ms")
     log(f"[serve-sam] engine stats: {eng.stats}")
     return results, eng.stats
 
