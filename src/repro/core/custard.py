@@ -35,9 +35,9 @@ from . import graph as g
 from . import streams as st
 from .einsum import Access, Assignment, Term, parse
 from .fibertree import spec_of
-from .schedule import (Format, Schedule, build_inputs, split_assignment,
-                       split_dims, split_format, split_schedule,
-                       unsplit_result)
+from .schedule import (Format, Schedule, build_input, build_inputs,
+                       input_accesses, split_assignment, split_dims,
+                       split_format, split_schedule, unsplit_result)
 
 Port = Tuple[g.Node, str]
 
@@ -586,6 +586,11 @@ class Lowered:
     def build_inputs(self, arrays) -> Dict[str, "FiberTree"]:
         return build_inputs(self.assign, self.fmt, self.schedule, arrays,
                             split_of=self.split_of)
+
+    def build_input(self, name: str, arrays) -> "FiberTree":
+        """The concordant FiberTree of input tensor ``name`` alone."""
+        return build_input(input_accesses(self.assign)[name], self.fmt,
+                           self.schedule, arrays, split_of=self.split_of)
 
     def unsplit(self, dense):
         """Map a dense result from post-split axes (lhs order) back to the

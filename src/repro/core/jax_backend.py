@@ -67,6 +67,12 @@ every graph node runs under a ``jax.named_scope`` ``sam.<kind>.n<id>``
 under ``sam.merge``), so device ops carry the node they compute; the
 eager capacity pass runs with no scope. ``stats["caps_passes"]`` and
 ``stats["caps_s"]`` count the capacity passes and their seconds.
+
+**Encode reuse.** ``CompiledExpr`` keeps the raw levels of every live
+operand array it has encoded into a compressed level, and serves that
+same object again from them when an exact check (under
+``sam.encode.reuse``) shows its contents unchanged; see ``_Encoded``.
+``stats["encode_reuse_hits"]``/``["encode_reuse_misses"]`` count it.
 """
 from __future__ import annotations
 
@@ -74,6 +80,7 @@ import contextlib
 import dataclasses
 import functools
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -89,7 +96,7 @@ from . import graph as g
 from .custard import expr_cache_key, lower
 from .einsum import Assignment, parse
 from .fibertree import BITVECTOR, COMPRESSED, DENSE, FiberTree, canonical_tree
-from .schedule import Format, Schedule
+from .schedule import Format, Schedule, input_accesses
 
 PAD = co.PAD_KEY
 
@@ -676,6 +683,62 @@ def _raw_flat_of(ft: FiberTree) -> Dict[str, Any]:
             "vals": np.asarray(ft.vals, np.float32)}
 
 
+def _reusable(arr) -> bool:
+    """An operand whose contents ``_Encoded.holds`` can check exactly: a
+    numpy array of a bool, integer or float dtype of 1, 2, 4 or 8 bytes."""
+    return (isinstance(arr, np.ndarray) and arr.dtype.kind in "biuf"
+            and arr.dtype.itemsize in (1, 2, 4, 8))
+
+
+def _bits(arr: np.ndarray) -> np.ndarray:
+    """A C-contiguous array's elements, flat, as integers of their own
+    width: two elements are equal bit for bit exactly when these are."""
+    return arr.reshape(-1).view(np.dtype(f"i{arr.dtype.itemsize}"))
+
+
+def _nonzero_bits(arr: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """Flat positions of a C-contiguous array's nonzero bit patterns and
+    those patterns, read-only."""
+    bits = _bits(arr)
+    pos = np.flatnonzero(bits)
+    at = bits[pos]
+    pos.setflags(write=False)
+    at.setflags(write=False)
+    return pos, at
+
+
+@dataclasses.dataclass(frozen=True)
+class _Encoded:
+    """The raw levels (``_raw_flat_of``) of one operand array object that
+    an engine has encoded, kept while the array lives.
+
+    From the object's second encode on, the entry also holds the flat
+    positions of its nonzero bit patterns and those patterns (the values
+    in the array's own dtype, compared as integers of their width), and
+    ``holds`` can prove that the array is still, bit for bit, what the
+    raw levels were built from: the same object, shape and dtype, as
+    many nonzero bit patterns as stored, and the stored ones at the
+    stored positions. Encoding reads nothing but the array's contents,
+    shape and dtype, so the raw levels are then what a rebuild would
+    give. Any write that changes a bit (a ``-0.0`` or a ``NaN`` too)
+    fails the check, and the operand is rebuilt."""
+    ref: weakref.ref
+    shape: Tuple[int, ...]
+    dtype: np.dtype
+    raw: Dict[str, Any]                   # read-only arrays
+    pos: Optional[np.ndarray] = None
+    at: Optional[np.ndarray] = None
+
+    def holds(self, arr: np.ndarray) -> bool:
+        if (self.pos is None or self.ref() is not arr
+                or arr.shape != self.shape or arr.dtype != self.dtype
+                or not arr.flags.c_contiguous):
+            return False
+        bits = _bits(arr)
+        return (np.count_nonzero(bits) == self.pos.shape[0]
+                and np.array_equal(bits[self.pos], self.at))
+
+
 def _pad_flat_arrays(raw, level_meta, hints=None):
     """Pad raw operand arrays to power-of-two buckets (shared by the
     expression engine and the program chain engine).
@@ -830,6 +893,7 @@ class CompiledExpr:
             self._intersect = kops.plan_primitive("sorted_intersect")
             self._union_reduce = kops.plan_primitive("keyed_union_reduce")
             self._mul_reduce = kops.plan_primitive("mul_reduce")
+        self._inputs = tuple(input_accesses(low.assign))
         self._level_meta: Dict[str, List[Tuple[str, int]]] = {}
         self._plans: Dict[Tuple, _Plan] = {}
         self._batch_plans: Dict[Tuple, _Plan] = {}
@@ -840,10 +904,16 @@ class CompiledExpr:
         # lands in a new bucket combination pays a fresh vmapped XLA
         # compile. Monotone hints pin the batch signature after warmup.
         self._hint_highwater: Dict[str, List[int]] = {}
+        # Raw levels of the live operand arrays encoded so far, one entry
+        # per (tensor name, array object); an entry leaves with its array
+        # (weakref callback). Only single dict operations touch it, which
+        # keeps concurrent callers safe without a lock.
+        self._encoded: Dict[Tuple[str, int], _Encoded] = {}
         self.stats = {"traces": 0, "plan_hits": 0, "plan_misses": 0,
                       "overflow_retries": 0, "calls": 0, "batch_calls": 0,
                       "lane_dispatches": 0, "sharded_dispatches": 0,
-                      "caps_passes": 0, "caps_s": 0.0}
+                      "caps_passes": 0, "caps_s": 0.0,
+                      "encode_reuse_hits": 0, "encode_reuse_misses": 0}
 
     @property
     def lane_devices(self) -> List[Any]:
@@ -871,14 +941,60 @@ class CompiledExpr:
         return merge
 
     # -- operand flattening ------------------------------------------------
-    def _raw_flat(self, arrays: Dict[str, np.ndarray]) -> Dict[str, Any]:
-        tensors = self.low.build_inputs(arrays)
-        raw = {}
-        for name, ft in tensors.items():
-            ft = _engine_tree(ft)   # s/h/m storage canonicalizes to d/c
-            self._level_meta.setdefault(
-                name, [(lv.format, lv.dim) for lv in ft.levels])
-            raw[name] = _raw_flat_of(ft)
+    def _raw_flat(self, arrays: Dict[str, np.ndarray],
+                  memo: Optional[Dict] = None) -> Dict[str, Any]:
+        """Raw levels of every input tensor. ``memo`` is shared by the
+        members of one dispatch: members that pass the same array object
+        share one build or one check."""
+        memo = {} if memo is None else memo
+        return {name: self._raw_tensor(name, arrays, memo)
+                for name in self._inputs}
+
+    def _build_raw(self, name: str, arrays) -> Dict[str, Any]:
+        ft = _engine_tree(self.low.build_input(name, arrays))  # s/h/m -> d/c
+        self._level_meta.setdefault(
+            name, [(lv.format, lv.dim) for lv in ft.levels])
+        return _raw_flat_of(ft)
+
+    def _raw_tensor(self, name: str, arrays, memo: Dict) -> Dict[str, Any]:
+        """Raw levels of one tensor, reused where the engine has encoded
+        this array object before and ``_Encoded.holds`` proves it
+        unchanged. Only numpy operands with a compressed level take part
+        (``encode_reuse_hits``/``_misses`` count them per member); others
+        are built as they come."""
+        arr = arrays[name]
+        meta = self._level_meta.get(name)
+        if not _reusable(arr) or (
+                meta is not None and COMPRESSED not in (f for f, _ in meta)):
+            return self._build_raw(name, arrays)
+        key = (name, id(arr))
+        raw = memo.get(key)
+        entry = self._encoded.get(key) if raw is None else None
+        if entry is not None and entry.pos is not None:
+            with TraceAnnotation("sam.encode.reuse", tensor=name):
+                if entry.holds(arr):
+                    raw = entry.raw
+        if raw is not None:
+            self.stats["encode_reuse_hits"] += 1
+            memo[key] = raw
+            return raw
+        # seen before: take the positions the next check needs (a fresh
+        # object pays for none)
+        seen = (entry is not None and entry.ref() is arr
+                and arr.flags.c_contiguous)
+        pos, at = _nonzero_bits(arr) if seen else (None, None)
+        raw = self._build_raw(name, arrays)
+        if COMPRESSED not in (f for f, _ in self._level_meta[name]):
+            return raw
+        self.stats["encode_reuse_misses"] += 1
+        for a in (*raw["segs"], *raw["crds"], raw["vals"]):
+            a.setflags(write=False)     # shared from here on
+        memo[key] = raw
+        if arr.flags.c_contiguous:
+            table = self._encoded
+            ref = (entry.ref if seen else weakref.ref(
+                arr, lambda _, k=key: table.pop(k, None)))
+            table[key] = _Encoded(ref, arr.shape, arr.dtype, raw, pos, at)
         return raw
 
     def _pad_flat(self, raw, hints=None):
@@ -1208,7 +1324,8 @@ class CompiledExpr:
         single plan instead of re-tracing per request."""
         if not arrays_list:
             return []
-        raws = [self._raw_flat(a) for a in arrays_list]
+        memo: Dict = {}
+        raws = [self._raw_flat(a, memo) for a in arrays_list]
         hints = self._sticky_hints(raws)
         out = []
         for raw in raws:
@@ -1226,10 +1343,10 @@ class CompiledExpr:
         the batch axis to a power of two, and stack. The result feeds
         ``execute_encoded``; no device compute beyond the array uploads
         happens here."""
-        raws = []
+        raws, memo = [], {}
         for a in arrays_list:
             with TraceAnnotation("sam.encode.build"):
-                raws.append(self._raw_flat(a))
+                raws.append(self._raw_flat(a, memo))
         with TraceAnnotation("sam.encode.pack"):
             hints = self._sticky_hints(raws)
             # largest-nnz member, recorded pre-padding: capacity recording

@@ -20,7 +20,7 @@ from typing import Dict, FrozenSet, Optional, Sequence, Tuple
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from .einsum import Assignment
+from .einsum import Access, Assignment
 from .fibertree import FiberTree
 
 
@@ -228,22 +228,33 @@ def split_format(assign: Assignment, fmt: Format, schedule: Schedule
     return Format(out, default=fmt.default)
 
 
+def input_accesses(assign: Assignment) -> Dict[str, Access]:
+    """The first access of each input tensor, in the order the terms
+    name them."""
+    out: Dict[str, Access] = {}
+    for term in assign.terms:
+        for acc in term.factors:
+            out.setdefault(acc.tensor, acc)
+    return out
+
+
+def build_input(acc: Access, fmt: Format, schedule: Schedule,
+                arrays: Dict[str, np.ndarray],
+                split_of: Optional[Dict[str, int]] = None) -> FiberTree:
+    """The concordant FiberTree of one input access, under a
+    ``sam.encode.tree`` profiler span whose ``tensor`` stat names it."""
+    with TraceAnnotation("sam.encode.tree", tensor=acc.tensor):
+        return _concordant_tree(acc, fmt, schedule, arrays, split_of or {})
+
+
 def build_inputs(assign: Assignment, fmt: Format, schedule: Schedule,
                  arrays: Dict[str, np.ndarray],
                  split_of: Optional[Dict[str, int]] = None
                  ) -> Dict[str, FiberTree]:
-    """Construct concordant FiberTrees for every input tensor, each under
-    a ``sam.encode.tree`` profiler span whose ``tensor`` stat names it."""
-    out: Dict[str, FiberTree] = {}
-    split_of = split_of or {}
-    for term in assign.terms:
-        for acc in term.factors:
-            if acc.tensor in out:
-                continue
-            with TraceAnnotation("sam.encode.tree", tensor=acc.tensor):
-                out[acc.tensor] = _concordant_tree(acc, fmt, schedule,
-                                                   arrays, split_of)
-    return out
+    """Construct concordant FiberTrees for every input tensor
+    (``build_input`` of each)."""
+    return {name: build_input(acc, fmt, schedule, arrays, split_of)
+            for name, acc in input_accesses(assign).items()}
 
 
 def _concordant_tree(acc, fmt: Format, schedule: Schedule,
