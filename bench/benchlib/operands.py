@@ -1,5 +1,13 @@
 """Operands drawn from the seed, as a configuration's ``operands`` state.
 
+Each operand names its ``kind``, and ``generators/<kind>.py`` draws it:
+a module with ``draw(spec, gen) -> Operand``, where ``spec`` is the
+operand's entry in the configuration (``shape``, ``dtype``, ``nnz`` and
+whatever else its kind reads) and ``gen`` a ``numpy.random.Generator``
+of the operand's own stream. A new kind is a new file. A generator of a
+sparse kind returns its COO and never a dense array: the engine module
+asks ``Operand.to_dense`` for one where its requests carry it.
+
 Every draw has a stream of its own, ``(seed, *stream)``, so the same seed
 gives the same operands whatever order the client threads run in:
 
@@ -12,17 +20,16 @@ gives the same operands whatever order the client threads run in:
   request, so no operand object or content is seen twice in a run;
 * ``SAMPLE``: ``(seed, 3)`` — which answers are compared, where a
   configuration compares a sample.
-
-A ``sparse`` operand has ``nnz`` nonzeros at distinct uniform random
-positions with values uniform in [-1, 1] (never 0); a ``dense`` one is
-uniform in [-1, 1] everywhere.
 """
 from __future__ import annotations
 
 import dataclasses
+from pathlib import Path
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
+
+from . import spec as specs
 
 SHARED, WARM, FRESH, SAMPLE = 0, 1, 2, 3
 
@@ -33,49 +40,34 @@ def rng(seed: int, *stream: int) -> np.random.Generator:
 
 @dataclasses.dataclass
 class Operand:
-    """One operand: its dense float32 array (what the serving API takes)
-    and, for a sparse one, its COO triplets (what the reference reads)."""
+    """One operand: a dense one's array, or a sparse one's COO (one
+    coordinate array per dimension, then the values), which is what the
+    reference reads."""
     shape: Tuple[int, ...]
     dense: Optional[np.ndarray]
-    coo: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]] = None
+    coo: Optional[Tuple[np.ndarray, ...]] = None
 
     def to_dense(self) -> np.ndarray:
+        """The dense array; a sparse operand's is built anew on each
+        call, and not kept."""
         if self.dense is not None:
             return self.dense
-        rows, cols, vals = self.coo
-        out = np.zeros(self.shape, np.float32)
-        out[rows, cols] = vals
+        *coords, vals = self.coo
+        out = np.zeros(self.shape, vals.dtype)
+        out[tuple(coords)] = vals
         return out
 
-    def drop_dense(self) -> None:
-        """Free a sparse operand's dense array; the COO stays."""
-        if self.coo is not None:
-            self.dense = None
 
-
-def draw(spec: Dict[str, Any], gen: np.random.Generator) -> Operand:
-    shape = tuple(int(d) for d in spec["shape"])
-    dtype = np.dtype(spec.get("dtype", "float32"))
-    if spec["kind"] == "dense":
-        return Operand(shape, gen.uniform(-1.0, 1.0, shape).astype(dtype))
-    if spec["kind"] != "sparse" or len(shape) != 2:
-        raise ValueError(f"operand kind {spec['kind']!r} of rank "
-                         f"{len(shape)} is not drawn here")
-    n_rows, n_cols = shape
-    flat = np.sort(gen.choice(n_rows * n_cols, size=int(spec["nnz"]),
-                              replace=False))
-    vals = gen.uniform(-1.0, 1.0, flat.size).astype(dtype)
-    vals[vals == 0] = 1
-    rows, cols = np.divmod(flat, n_cols)
-    op = Operand(shape, None, (rows, cols, vals))
-    op.dense = op.to_dense()
-    return op
+def draw(spec: Dict[str, Any], gen: np.random.Generator,
+         bench: Path = specs.BENCH) -> Operand:
+    """One operand of ``spec``, drawn by ``generators/<kind>.py``."""
+    return specs.module("generators", spec["kind"], bench).draw(spec, gen)
 
 
 def draw_all(config: Dict[str, Any], share: str, seed: int,
-             *stream: int) -> Dict[str, Operand]:
+             *stream: int, bench: Path = specs.BENCH) -> Dict[str, Operand]:
     """Every operand of ``config`` whose ``share`` is ``share``, each from
     stream ``(seed, *stream, its index in the configuration)``."""
-    return {name: draw(spec, rng(seed, *stream, i))
+    return {name: draw(spec, rng(seed, *stream, i), bench)
             for i, (name, spec) in enumerate(config["operands"].items())
             if spec["share"] == share}

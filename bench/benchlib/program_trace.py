@@ -202,8 +202,8 @@ def name_gap(gap: Interval, spans: List[Span]) -> str:
 
 def reduce(xspace: bytes, open_seq: int, close_seq: int,
            dispatch_n: Dict[int, int]) -> Optional[Dict]:
-    """The program's view of the window that the ``bench.decode`` spans
-    of dispatches ``open_seq`` and ``close_seq`` bound, from the
+    """The program's view of the window that the ends of dispatches
+    ``open_seq`` and ``close_seq`` bound (``trace.dispatch_ends``), from the
     serialized trace ``xspace``; ``dispatch_n`` maps each of the
     window's dispatches to its requests. None when the trace holds no
     ``sam.*`` stage spans for the window's dispatches or their ``n``
@@ -211,8 +211,7 @@ def reduce(xspace: bytes, open_seq: int, close_seq: int,
     from jax.profiler import ProfileData
 
     profile = ProfileData.from_serialized_xspace(xspace)
-    ends = {seq: e for stage, seq, _, e in trace.host_spans(profile)
-            if stage == "decode"}
+    ends = trace.dispatch_ends(trace.host_spans(profile))
     ops = scoped_ops(profile, op_paths(xspace))
     if open_seq not in ends or close_seq not in ends or not ops:
         return None

@@ -3,7 +3,17 @@
 ``BENCHMARK.json`` (at the checkout's root) names every cell, with its
 configuration and traffic mix; each of those is a file of its own:
 
-* ``configs/<config>.json``  — expression, formats, schedule, operands;
+* ``configs/<config>.json``  — expression, formats, schedule, operands,
+  and the names of its ``engine``, ``work`` and ``reference`` files;
+  ``tiny`` holds its size for the tests on the CPU: ``dims``, and per
+  operand its ``shape`` and, where it has one, ``nnz``;
+* ``engines/<engine>.py``    — the engine and its requests: ``build(config)``
+  returns the engine ``SamServer`` serves, ``arrays(operands)`` maps
+  drawn operands to what a ``Request`` carries, ``request(config,
+  arrays)`` returns the ``Request``, and ``answer(result)`` the served
+  result as the array the reference returns (``load.py``);
+* ``generators/<kind>.py``   — ``draw(spec, gen)``: one operand of that
+  ``kind`` (``operands.py``);
 * ``traffic/<traffic>.json`` — clients and the server's ``max_batch``;
 * ``metrics/<metric>.py``    — one per-layer metric's reader;
 * ``work/<name>.py``         — FLOPs and minimum bytes of one request;
@@ -11,10 +21,12 @@ configuration and traffic mix; each of those is a file of its own:
 * ``peaks.json``             — the chip's peaks, keyed by device kind.
 
 A later cell adds files and entries; none of these functions changes.
+A module file is loaded once per process and path.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -75,6 +87,11 @@ def module(kind: str, name: str, bench: Path = BENCH) -> ModuleType:
     path = Path(bench) / kind / f"{name}.py"
     if not path.is_file():
         raise KeyError(f"no {kind} file {path}")
+    return _load(path, kind, name)
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: Path, kind: str, name: str) -> ModuleType:
     mod_spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{name}".replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(mod_spec)

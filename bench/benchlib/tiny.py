@@ -1,21 +1,29 @@
 """Tiny copies of the benchmark's configurations, for the tests on the
-CPU: the same expressions, formats, schedules and references at a few
-dozen nonzeros, and a short traffic mix."""
+CPU: the same expressions, formats, schedules, engines and references at
+the size each configuration's ``tiny`` entry gives (``dims``, and per
+operand ``shape`` and, where it has one, ``nnz``), and a short traffic
+mix. A configuration added as a file is cut and tested like the rest."""
 from __future__ import annotations
 
 import json
 import shutil
 from pathlib import Path
-from typing import Dict
+from typing import Any, Dict, List
 
 from . import spec
 
-SIZES: Dict[str, Dict] = {
-    "spmv-rail507": {"dims": {"i": 8, "j": 48},
-                     "operands": {"B": ([8, 48], 40), "c": ([48], None)}},
-    "spmm-g42": {"dims": {"i": 24, "k": 24, "j": 24},
-                 "operands": {"B": ([24, 24], 40), "C": ([24, 24], 40)}},
-}
+
+def configs(bench: Path = spec.BENCH) -> List[str]:
+    """The name of every configuration file under ``bench``, sorted."""
+    return sorted(p.stem for p in (Path(bench) / "configs").glob("*.json"))
+
+
+def cut(config: Dict[str, Any]) -> Dict[str, Any]:
+    """``config`` at its ``tiny`` size."""
+    size = config["tiny"]
+    return dict(config, dims=size["dims"], operands={
+        name: dict(op, **size["operands"][name])
+        for name, op in config["operands"].items()})
 
 
 def tiny_bench(dst: Path, clients: int = 4, max_batch: int = 4) -> Path:
@@ -24,15 +32,9 @@ def tiny_bench(dst: Path, clients: int = 4, max_batch: int = 4) -> Path:
     dst = Path(dst)
     shutil.copytree(spec.BENCH, dst,
                     ignore=shutil.ignore_patterns("__pycache__", "test_*"))
-    for name, size in SIZES.items():
+    for name in configs(dst):
         path = dst / "configs" / f"{name}.json"
-        config = json.loads(path.read_text())
-        config["dims"] = size["dims"]
-        for op, (shape, nnz) in size["operands"].items():
-            config["operands"][op]["shape"] = shape
-            if nnz is not None:
-                config["operands"][op]["nnz"] = nnz
-        path.write_text(json.dumps(config))
+        path.write_text(json.dumps(cut(json.loads(path.read_text()))))
     (dst / "traffic" / "tiny.json").write_text(
         json.dumps({"clients": clients, "max_batch": max_batch}))
     return dst

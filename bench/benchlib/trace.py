@@ -5,11 +5,13 @@ its longest idle gaps.
 * Device busy: the union of the event intervals on the ``XLA Ops`` line
   of every ``/device:TPU:<n>`` plane, clipped to the window and averaged
   over the chips.
-* The window: from the end of the opening dispatch's decode span to the
-  end of the closing one's. The spans are ``bench.<stage>.<seq>``
-  annotations that ``load.py`` puts around the engine's ``encode_batch``,
-  ``execute_encoded`` and ``decode_batch``; host planes are read for
-  these spans only.
+* The window: from the end of the opening dispatch's last ``bench.*``
+  span to the end of the closing one's: its decode span where it has
+  one, else its execute span. The spans are ``bench.<stage>.<seq>``
+  annotations that ``load.Instrument`` puts around the engine calls the
+  server makes (``encode_batch``, ``execute_encoded`` and
+  ``decode_batch``, or the one call of another engine kind); host planes
+  are read for these spans only.
 * Each idle gap is named by the stages whose spans cover at least half
   of it, or else by the one that covers most of it, or ``none``.
 
@@ -52,6 +54,14 @@ def host_spans(profile) -> List[Tuple[str, int, float, float]]:
                     out.append((m.group(1), int(m.group(2)),
                                 ev.start_ns, ev.start_ns + ev.duration_ns))
     return out
+
+
+def dispatch_ends(spans) -> Dict[int, float]:
+    """Each dispatch's end: the end of its last ``bench.*`` span."""
+    ends: Dict[int, float] = {}
+    for _, seq, _, e in spans:
+        ends[seq] = max(e, ends.get(seq, e))
+    return ends
 
 
 def op_name(event_name: str) -> str:
@@ -117,9 +127,9 @@ def name_gap(gap: Interval, spans: Dict[str, List[Interval]]) -> str:
 
 def reduce(profile, open_seq: int, close_seq: int) -> Optional[Dict]:
     """Busy and window seconds and the breakdown of the window that the
-    decode spans of dispatches ``open_seq`` and ``close_seq`` bound."""
+    ends of dispatches ``open_seq`` and ``close_seq`` bound."""
     spans = host_spans(profile)
-    ends = {seq: e for stage, seq, _, e in spans if stage == "decode"}
+    ends = dispatch_ends(spans)
     ops = device_ops(profile)
     if open_seq not in ends or close_seq not in ends or not ops:
         return None

@@ -1,7 +1,9 @@
 """The measured window, opened and closed on dispatch completions.
 
-A dispatch completes when ``decode_batch`` returns its results, just
-before the server hands them to their requests. The window opens at the
+A dispatch completes when the last of the engine's calls for it
+returns (``decode_batch``, or the single call of an engine kind with no
+stages, ``load.Instrument``), just before the server hands the results
+to their requests. The window opens at the
 first completion of the load and closes at the first completion at least
 ``seconds`` later. Every request of the dispatches completed in
 ``(open, close]`` counts, over the whole of ``close - open``: a stall
@@ -18,10 +20,10 @@ from typing import Callable, List, Optional, Sequence
 @dataclasses.dataclass
 class Dispatch:
     seq: int            # order in which the dispatch was encoded
-    t_done: float       # when decode_batch returned
+    t_done: float       # when its last engine call returned
     n: int              # live requests in it
-    encode_s: float     # its encode_batch span
-    decode_s: float     # its decode_batch span
+    encode_s: Optional[float]   # its encode_batch span, if it has one
+    decode_s: Optional[float]   # its decode_batch span, if it has one
 
 
 @dataclasses.dataclass

@@ -11,7 +11,9 @@ warmed), drives closed-loop load through ``SamServer`` for a window of
 every served answer with the float64 reference, and prints one JSON
 line last on stdout: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
-per-layer ones, read from a profiler trace of the window), ``device``,
+per-layer ones, read from a profiler trace of the window), ``device``
+(``memory_peak_bytes`` is the peak of the fullest of the cell's chips,
+``memory_peak_bytes_per_chip`` each one's),
 ``breakdown`` (traced runs) and ``checks`` (each number compared, with
 its limit; also the last lines on stderr). Without a TPU, or with fewer
 chips than the cell asks for, it exits 1 and prints no result.
@@ -48,7 +50,8 @@ def result_line(cell: spec.Cell, rec, device, trace: bool) -> dict:
             if hasattr(reader, "note"):
                 print(f"bench: {m['name']}: {reader.note(rec)}",
                       file=sys.stderr)
-    dev = dict(device, memory_peak_bytes=rec["memory_peak_bytes"])
+    dev = dict(device, memory_peak_bytes=rec["memory_peak_bytes"],
+               memory_peak_bytes_per_chip=rec["memory_peak_bytes_per_chip"])
     line = {"correct": rec["correct"], "attempted": rec["attempted"],
             "failed": rec["failed"], "metrics": metrics, "device": dev}
     if trace and rec["trace"]:

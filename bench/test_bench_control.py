@@ -7,7 +7,6 @@ import sys
 import threading
 from pathlib import Path
 
-import numpy as np
 import pytest
 
 BENCH = Path(__file__).resolve().parent
@@ -26,7 +25,7 @@ def bench(tmp_path_factory):
     return tiny.tiny_bench(tmp_path_factory.mktemp("control") / "bench")
 
 
-@pytest.mark.parametrize("config", sorted(tiny.SIZES))
+@pytest.mark.parametrize("config", tiny.configs())
 def test_control_in_the_programs_place_is_not_correct(bench, config,
                                                       monkeypatch):
     cell = tiny.tiny_cell(bench, config)
@@ -56,22 +55,21 @@ def test_control_in_the_programs_place_is_not_correct(bench, config,
     assert err["value"] > err["limit"]
 
 
-@pytest.mark.parametrize("config", sorted(tiny.SIZES))
+@pytest.mark.parametrize("config", tiny.configs())
 def test_control_reads_far_above_the_program(config):
-    """Directly on one tiny operand set: the program's float32 answer
-    reads well under the limit, the control's well over it."""
-    bench_config = spec.load_json(spec.BENCH / "configs" / f"{config}.json")
+    """Directly on one tiny operand set: the program's float32 answer,
+    served through a synchronous server, reads well under the limit, the
+    control's well over it."""
+    bench_config = tiny.cut(
+        spec.load_json(spec.BENCH / "configs" / f"{config}.json"))
     ref = spec.module("reference", bench_config["reference"])
-    size = tiny.SIZES[config]
-    ops = {}
-    for i, (name, o) in enumerate(bench_config["operands"].items()):
-        shape, nnz = size["operands"][name]
-        ops[name] = operands.draw(dict(o, shape=shape, nnz=nnz),
-                                  operands.rng(5, 9, i))
+    engine = spec.module("engines", bench_config["engine"])
+    ops = {name: operands.draw(o, operands.rng(5, 9, i))
+           for i, (name, o) in enumerate(bench_config["operands"].items())}
     truth = ref.reference(ops)
-    dense = {n: o.to_dense().astype(np.float32) for n, o in ops.items()}
-    names = list(dense)
-    f32 = dense[names[0]] @ dense[names[1]]
+    eng = engine.build(bench_config)
+    served = load.serve_sync(eng, engine, bench_config,
+                             [engine.arrays(ops)])
     limit = bench_config["limits"]["max_rel_err"]
-    assert check.rel_error(f32, truth) < limit / 10
+    assert check.rel_error(engine.answer(served[0]), truth) < limit / 10
     assert check.rel_error(ref.control(ops), truth) > limit * 3

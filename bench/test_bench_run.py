@@ -31,7 +31,7 @@ def test_main_off_a_tpu_exits_nonzero_and_prints_no_result(capsys):
     assert "not 'tpu'" in err
 
 
-@pytest.mark.parametrize("config", sorted(tiny.SIZES))
+@pytest.mark.parametrize("config", tiny.configs())
 def test_tiny_run_is_correct_with_the_contract_keys(bench, config):
     cell = tiny.tiny_cell(bench, config)
     rec = load.run(cell, 2**31 + 11, 1.0, trace=False, t_process=0.0)
@@ -43,7 +43,11 @@ def test_tiny_run_is_correct_with_the_contract_keys(bench, config):
     assert set(line["metrics"]) == {"req_per_s", "setup_s"}
     assert all(m["value"] > 0 for m in line["metrics"].values())
     assert set(line["device"]) == {"platform", "kind", "count",
-                                   "memory_peak_bytes"}
+                                   "memory_peak_bytes",
+                                   "memory_peak_bytes_per_chip"}
+    assert line["device"]["memory_peak_bytes_per_chip"] == \
+        rec["memory_peak_bytes_per_chip"]
+    assert len(rec["memory_peak_bytes_per_chip"]) == cell.chips
     assert line["checks"]["max_rel_err"]["value"] < 1e-5
     json.loads(json.dumps(line))
 
@@ -53,7 +57,8 @@ def test_traced_line_carries_busy_window_and_breakdown(bench):
     rec = {
         "correct": False, "attempted": 5, "failed": 1,
         "checks": {"max_rel_err": {"value": 1.0, "limit": 1e-4}},
-        "memory_peak_bytes": 123, "setup_s": 9.0, "peaks":
+        "memory_peak_bytes": 123, "memory_peak_bytes_per_chip": [123],
+        "setup_s": 9.0, "peaks":
             spec.peaks("TPU v5 lite"),
         "window": {"seconds": 2.0, "requests": 4, "dispatches": 2,
                    "encode_s": 0.4, "decode_s": 0.2, "compiles": 0},

@@ -47,8 +47,10 @@ def test_config_and_traffic_added_as_files_are_found_by_name(copy):
     assert cell.traffic == {"clients": 3, "max_batch": 2}
     assert cell.bench == copy
     # the new cell reports every metric that names no workloads
-    assert {m["name"] for m in cell.end_to_end} == {"req_per_s", "setup_s"}
-    assert len(cell.per_layer) == len(SPEC["per_layer"])
+    assert [m["name"] for m in cell.end_to_end] == [
+        m["name"] for m in SPEC["end_to_end"] if "workloads" not in m]
+    assert [m["name"] for m in cell.per_layer] == [
+        m["name"] for m in SPEC["per_layer"] if "workloads" not in m]
 
 
 def test_metric_work_and_reference_added_as_files_are_found_by_name(copy):
@@ -93,6 +95,13 @@ def test_every_name_of_benchmark_json_is_a_file_with_a_reader():
         assert body["reduced"] == c["reduced"]
         spec.module("work", body["work"])
         spec.module("reference", body["reference"])
+        engine = spec.module("engines", body["engine"])
+        assert all(callable(getattr(engine, f)) for f in
+                   ("build", "arrays", "request", "answer"))
+        for name, op in body["operands"].items():
+            assert callable(spec.module("generators", op["kind"]).draw)
+            assert set(body["tiny"]["operands"][name]) <= {"shape", "nnz"}
+        assert set(body["tiny"]["dims"]) == set(body["dims"])
     names = [m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]]
     names += [w["name"] for w in SPEC["workloads"]] + list(configs)
     assert len(names) == len(set(names))
@@ -114,7 +123,9 @@ def test_bounds_and_run_length_keep_to_their_ranges():
     for m in SPEC["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.25
         assert m["source"] in ("host_clock", "device_trace")
-    assert all(w["chips"] == 1 for w in SPEC["workloads"])
+    four = sum(w["chips"] == 4 for w in SPEC["workloads"])
+    assert all(w["chips"] in (1, 4) for w in SPEC["workloads"])
+    assert four <= max(1, len(SPEC["workloads"]) // 2)
     assert len(json.dumps(SPEC)) < 64 * 1024
     texts = [e[k] for e in SPEC["configs"] + SPEC["workloads"]
              + SPEC["per_layer"] for k in ("why", "source", "layer")

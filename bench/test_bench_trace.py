@@ -74,6 +74,20 @@ def test_reduce_a_window_between_decode_spans():
     assert gaps["encode"] == pytest.approx(250e-9)       # [150, 400)
 
 
+def test_reduce_a_window_between_execute_spans():
+    """An engine kind with no decode stage: a dispatch ends with its
+    execute span, and the window runs between those ends."""
+    host = [("bench.execute.1", 100, 200), ("bench.execute.2", 300, 300),
+            ("bench.execute.3", 600, 300)]
+    dev = [("fusion.1", 350, 200), ("fusion.1", 650, 100)]
+    got = trace.reduce(profile(dev, host), 1, 3)
+    assert got["window_s"] == pytest.approx(600e-9)      # [300, 900)
+    assert got["busy_s"] == pytest.approx(300e-9)
+    assert got["idle_gaps"][0] == ["execute", pytest.approx(150e-9)]
+    assert trace.dispatch_ends(trace.host_spans(profile(dev, host))) == {
+        1: 300, 2: 600, 3: 900}
+
+
 def test_reduce_without_device_plane_or_window_is_none():
     host = [("bench.decode.1", 0, 10), ("bench.decode.2", 50, 10)]
     assert trace.reduce(profile([("fusion", 0, 5)], host), 1, 7) is None
